@@ -111,6 +111,9 @@ def main(argv=None) -> int:
                     help="tuning-cache file --retune rewrites (default "
                          "TUNE_baseline.json at the cwd/repo root)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     unknown = [n for n in args.names if n not in ALL]
     if unknown:
         ap.error(f"unknown benchmarks {unknown}; have {ALL}")

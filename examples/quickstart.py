@@ -8,11 +8,11 @@ https://github.com/<org>/<repo>/actions/workflows/ci.yml/badge.svg
   job          what it proves
   -----------  ------------------------------------------------------
   lint         ruff correctness rules (ruff.toml) + compileall
-  tier1        full suite on jax 0.4.37 *and* 0.8.0 (compat shim
-               exercised both ways)
+  tier1        full suite on jax 0.9.0, real-width kernel compiles
+               for a described TPU v5e included
   parity       jnp oracle vs pallas-interpret bit-exactness sweep
-  multidevice  EP/TP shard_map tests on 8 fake XLA devices, both jax
-               pins — the kernels really run on local shards
+  multidevice  EP/TP shard_map tests on 8 fake XLA devices — the
+               kernels really run on local shards
   bench-gate   benchmarks.run --retune --smoke + regression diff
                against the committed BENCH_baseline.json, and a drift
                check that the retune reproduced TUNE_baseline.json
@@ -157,14 +157,15 @@ print("tuned 512^3 spec:", (auto.bm, auto.bn, auto.bk, auto.depth),
 # so the same pinned config routes jnp under pjit and pallas under
 # shard_map, and post-build env changes can't flip compiled kernels.
 #
+#   from repro.launch.mesh import make_local_mesh
 #   from repro.parallel.sharding import make_rules
-#   mesh = jax.make_mesh((2, 4), ("data", "model"))   # EP over "model"
+#   mesh = make_local_mesh(data=2, model=4)           # EP over "model"
 #   ctx = ParallelCtx(mesh, make_rules(cfg))
 #   out = jax.jit(lambda x, p: moe_ffn(x, p, cfg, ctx))(x, params)
 #
-# Locality is detected from the axis environment (works on jax 0.4.x and
-# 0.8+); shard_map bodies must run under jit — the eager shard_map
-# interpreter has no pallas rule.  CI's `multidevice` job forces an
+# Locality is detected from the axis environment; shard_map bodies
+# must run under jit — the eager shard_map interpreter has no pallas
+# rule.  CI's `multidevice` job forces an
 # 8-device CPU host (XLA_FLAGS=--xla_force_host_platform_device_count=8)
 # and checks the sharded EP/TP forward bit-exact against the
 # single-device oracle (tests/test_shardmap_parity.py).

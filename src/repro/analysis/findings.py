@@ -23,7 +23,7 @@ code do not churn the baseline:
     stripped source line — a *moved* escape still matches, a *second
     copy* of the same line is a new escape (multiset semantics);
   * jaxpr findings key on ``(entry, primitive, file)`` — trace-level
-    line attribution is too version-dependent (jax 0.4.x vs 0.8 lower
+    line attribution is too version-dependent (jax releases lower
     differently) to ratchet on, but a dot_general/div escaping in a
     file that had none is always a failure.  Count *increases* within
     an existing key are reported as warnings.

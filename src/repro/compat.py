@@ -1,33 +1,19 @@
-"""Version-compatibility layer over the jax API surface this repo uses.
+"""Thin layer over the jax API surface this repo shard-maps through.
 
-The repo targets the jax 0.4.x series that ships in the hermetic image
-*and* the current 0.8+ API, which moved/renamed the things we depend on:
-
-  * ``shard_map`` — lives at ``jax.experimental.shard_map.shard_map`` on
-    0.4.x and was promoted to ``jax.shard_map`` on 0.8+;
-  * the replication-check kwarg — called ``check_rep`` on 0.4.x and
-    renamed to ``check_vma`` on 0.8+;
-  * the **named-axis environment** — the trace-time record of which mesh
-    axes the current code is manually mapped over (i.e. "am I inside a
-    shard_map body, and what are my local axis sizes").  Old 0.4.x
-    keeps a stack of ``AxisEnvFrame``s on
-    ``jax.core.thread_local_state.trace_state.axis_env``; 0.4.36+ and
-    0.8+ expose a single ``get_axis_env()`` returning an ``AxisEnv``
-    with an ``axis_sizes`` mapping.
-
-Everything that shard-maps goes through :func:`shard_map` below, which
-accepts the *new* spelling (``check_vma=``) and translates to whatever
-the installed jax understands.  The adapter is resolved once per process
-and cached; :func:`adapt_shard_map` is the pure, cache-free core so tests
-can exercise both signatures with monkeypatched implementations.
+The repo targets jax 0.9, where ``shard_map`` lives at ``jax.shard_map``
+and takes the replication-check flag as ``check_vma=``.  Everything
+that shard-maps goes through :func:`shard_map` below; the adapter
+forwards ``check_vma`` only to an implementation that accepts it, and
+:func:`adapt_shard_map` is the pure, cache-free core so tests can feed
+it monkeypatched implementations.
 
 Manual-mesh helpers: :func:`axis_env_sizes` / :func:`in_shard_map` /
 :func:`manual_axis_size` answer the locality question the backend
 registry needs — a Pallas kernel is per-device, so it is only legal on a
 multi-device process when the call site is already device-local (traced
 inside a shard_map body).  :func:`axis_env_reader_for` is the pure,
-cache-free core over a module-like surface, so tests can exercise the
-legacy-frames and modern-AxisEnv shapes against the same expectations.
+cache-free core over a ``jax.core``-like surface exposing
+``get_axis_env()``.
 """
 from __future__ import annotations
 
@@ -42,7 +28,6 @@ __all__ = [
     "adapt_shard_map",
     "resolve_shard_map",
     "axis_sizes_from_env",
-    "axis_sizes_from_frames",
     "axis_env_reader_for",
     "axis_env_sizes",
     "in_shard_map",
@@ -51,34 +36,25 @@ __all__ = [
 
 
 def resolve_shard_map() -> Callable:
-    """Locate the installed jax's shard_map implementation."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn  # jax <= 0.4.x
-
-    return fn
+    """The installed jax's shard_map implementation."""
+    return jax.shard_map
 
 
 def _check_kwarg_name(impl: Callable) -> Optional[str]:
-    """Which replication-check kwarg (if any) ``impl`` accepts."""
+    """``"check_vma"`` when ``impl`` accepts the flag, else ``None``."""
     try:
         params = inspect.signature(impl).parameters
     except (TypeError, ValueError):  # builtins / C impls: assume modern
         return "check_vma"
-    for name in ("check_vma", "check_rep"):
-        if name in params:
-            return name
-    return None
+    return "check_vma" if "check_vma" in params else None
 
 
 def adapt_shard_map(impl: Callable) -> Callable:
-    """Wrap a shard_map implementation behind the 0.8+ calling convention.
+    """Wrap a shard_map implementation behind one calling convention.
 
     The returned callable has signature
     ``(f, *, mesh, in_specs, out_specs, check_vma=None)`` and forwards the
-    check flag under whichever kwarg ``impl`` actually accepts (dropping
-    it entirely for implementations that accept neither).
+    check flag when ``impl`` accepts it (dropping it otherwise).
     """
     kwarg = _check_kwarg_name(impl)
 
@@ -97,7 +73,7 @@ def _cached_adapter() -> Callable:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map`` (accepts the 0.8+ ``check_vma=``)."""
+    """``jax.shard_map`` behind :func:`adapt_shard_map`."""
     return _cached_adapter()(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=check_vma,
@@ -105,14 +81,15 @@ def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
 
 
 # --------------------------------------------------------------------------
-# manual-mesh awareness: the named-axis environment, both API generations
+# manual-mesh awareness: the named-axis environment
 # --------------------------------------------------------------------------
 
 def axis_sizes_from_env(env) -> Dict[str, int]:
-    """Pure: modern ``AxisEnv`` (0.4.36+/0.8+) -> ``{axis_name: size}``.
+    """Pure: ``AxisEnv`` -> ``{axis_name: size}``.
 
-    The modern object carries an ``axis_sizes`` mapping; anything without
-    one (or ``None``) reads as "no axes bound".
+    The object carries an ``axis_sizes`` mapping; anything without one
+    (or ``None``) reads as "no axes bound".  Names that are not plain
+    strings (the sentinel an unnamed vmap binds) do not count.
     """
     sizes = getattr(env, "axis_sizes", None)
     if not sizes:
@@ -121,56 +98,30 @@ def axis_sizes_from_env(env) -> Dict[str, int]:
             if isinstance(name, str)}
 
 
-def axis_sizes_from_frames(frames) -> Dict[str, int]:
-    """Pure: legacy ``AxisEnvFrame`` stack (jax <= 0.4.35) -> sizes.
-
-    Frames whose name is not a plain string (e.g. the ``no_axis_name``
-    sentinel an unnamed vmap pushes) are skipped — only user-named mesh
-    axes count as manual-region evidence.
-    """
-    out: Dict[str, int] = {}
-    for frame in frames or ():
-        name = getattr(frame, "name", None)
-        size = getattr(frame, "size", None)
-        if isinstance(name, str) and size is not None:
-            out[name] = int(size)
-    return out
-
-
 def axis_env_reader_for(core) -> Callable[[], Dict[str, int]]:
     """Build an axis-size reader over a ``jax.core``-like surface.
 
-    ``core`` exposes either the modern ``get_axis_env()`` (an ``AxisEnv``
-    with ``axis_sizes``) or the legacy
-    ``thread_local_state.trace_state.axis_env`` frame stack; the returned
-    zero-arg callable yields ``{axis_name: size}`` either way.  Pure and
-    cache-free so tests can feed both API shapes through one contract.
+    ``core`` exposes ``get_axis_env()`` (an ``AxisEnv`` with
+    ``axis_sizes``); the returned zero-arg callable yields
+    ``{axis_name: size}``.  A surface without it is never inside a
+    manual region.
     """
     get_env = getattr(core, "get_axis_env", None)
-    if get_env is not None:
-        return lambda: axis_sizes_from_env(get_env())
-    tls = getattr(core, "thread_local_state", None)
-    if tls is not None:
-        return lambda: axis_sizes_from_frames(tls.trace_state.axis_env)
-    return dict  # no axis-env surface at all: never inside a manual region
+    if get_env is None:
+        return dict
+    return lambda: axis_sizes_from_env(get_env())
 
 
 def _installed_axis_env_reader() -> Callable[[], Dict[str, int]]:
-    """Locate the installed jax's axis environment (public surface first,
-    then the 0.4.36+/0.8 private home of ``get_axis_env``).
-
-    Resolved per call — the lookup is two ``getattr``s and happens at
-    trace time (not per element), and late binding keeps monkeypatched
-    ``jax.core`` surfaces in tests honest.
+    """The installed jax's axis environment (public ``jax.core`` first,
+    then its private home).  Resolved per call — it happens at trace
+    time, and late binding keeps monkeypatched ``jax.core`` surfaces in
+    tests honest.
     """
-    core = jax.core
-    if (getattr(core, "get_axis_env", None) is not None
-            or getattr(core, "thread_local_state", None) is not None):
-        return axis_env_reader_for(core)
-    try:
-        from jax._src import core as src_core
-    except ImportError:  # pragma: no cover - unknown future jax
-        return dict
+    if getattr(jax.core, "get_axis_env", None) is not None:
+        return axis_env_reader_for(jax.core)
+    from jax._src import core as src_core
+
     return axis_env_reader_for(src_core)
 
 
@@ -186,9 +137,8 @@ def axis_env_sizes() -> Dict[str, int]:
 
 def in_shard_map() -> bool:
     """Whether the current trace point is inside a manually-mapped
-    (device-local) region — a ``shard_map`` body on every supported jax
-    (``pmap`` and axis-named ``vmap`` also register; the repo uses
-    neither).
+    (device-local) region — a ``shard_map`` body (``pmap`` and
+    axis-named ``vmap`` also register; the repo uses neither).
     """
     return bool(axis_env_sizes())
 

@@ -41,6 +41,8 @@ __all__ = [
     "div_lut",
     "mul_lut_device",
     "div_lut_device",
+    "lut_take",
+    "kernel_lut",
     "log_mul_f32",
     "log_div_f32",
     "log_recip_f32",
@@ -97,18 +99,51 @@ def div_lut_device(scheme: ErrorScheme | str, dtype: str = "int32"):
     return _lut_device("div", _as_scheme("div", scheme), dtype)
 
 
+def lut_take(lut: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``lut[idx]`` for a 256-entry coefficient table.
+
+    A 1-D ``(256,)`` table is a plain XLA gather.  Pallas TPU kernels
+    pass the table as an ``(8, 256)`` tile of identical rows instead:
+    Mosaic gathers only within one vreg, and only from a source laid
+    out as whole sublane tiles, so each 128-lane block of the 2-D
+    ``idx`` looks up both 128-entry halves of the table and selects.
+    Both forms return the same integers, which keeps kernels bit-exact
+    with the jnp path.
+    """
+    if lut.ndim == 1:
+        return jnp.take(lut, idx)
+    rows, width = idx.shape
+    zeros = jnp.zeros((rows, 128), lut.dtype)
+    lo = lut[:1, :128] + zeros
+    hi = lut[:1, 128:] + zeros
+    blocks = []
+    for j in range(0, width, 128):
+        ij = idx[:, j:j + 128]
+        lane = ij & 127
+        blocks.append(jnp.where(
+            ij < 128,
+            jnp.take_along_axis(lo, lane, axis=1, mode="promise_in_bounds"),
+            jnp.take_along_axis(hi, lane, axis=1, mode="promise_in_bounds")))
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+
+
+def kernel_lut(lut: jnp.ndarray) -> jnp.ndarray:
+    """A ``(256,)`` table as the ``(8, 256)`` tile Pallas kernels take."""
+    return jnp.broadcast_to(lut.reshape(1, -1), (8, lut.size))
+
+
 def _log_mul_bits(m1: jnp.ndarray, m2: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
     """Magnitude-bits multiply in the log domain. m1, m2 >= 0 (int32)."""
     i1 = (m1 >> (_F32_FRAC - 4)) & 0xF
     i2 = (m2 >> (_F32_FRAC - 4)) & 0xF
-    c = jnp.take(lut, i1 * 16 + i2)
+    c = lut_take(lut, i1 * 16 + i2)
     return m1 + m2 - _F32_BIAS + c
 
 
 def _log_div_bits(m1: jnp.ndarray, m2: jnp.ndarray, lut: jnp.ndarray) -> jnp.ndarray:
     i1 = (m1 >> (_F32_FRAC - 4)) & 0xF
     i2 = (m2 >> (_F32_FRAC - 4)) & 0xF
-    c = jnp.take(lut, i1 * 16 + i2)
+    c = lut_take(lut, i1 * 16 + i2)
     return m1 - m2 + _F32_BIAS + c
 
 

@@ -267,11 +267,8 @@ def set_tuning_cache(cache: Optional[TuningCache]) -> None:
 
 @functools.lru_cache(maxsize=1)
 def _default_platform() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - no runtime at all
-        return "cpu"
+    import jax
+    return jax.default_backend()
 
 
 def cached_spec(family: str, shapes: Sequence[int], *,

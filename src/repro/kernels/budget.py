@@ -21,6 +21,7 @@ __all__ = [
     "LANE",
     "SUBLANE",
     "ELEM_BYTES",
+    "LUT_TILE",
     "VMEM_BUDGET_BYTES",
     "PIPELINE_BUFFERS",
     "ROW_SLAB_BYTES",
@@ -39,6 +40,11 @@ __all__ = [
 LANE = 128
 SUBLANE = 8
 ELEM_BYTES = 4
+
+# Every kernel holds its 256-entry coefficient table as one (8, 256)
+# int32 tile of identical rows: Mosaic gathers only within a vreg, from
+# a source laid out as whole sublane tiles (float_approx.lut_take).
+LUT_TILE = (SUBLANE, 256)
 
 # Per-core VMEM capacity the kernels budget against.  TPU cores carry
 # 16 MiB of VMEM; Mosaic's grid pipeline double-buffers every
@@ -87,8 +93,15 @@ def tile_bytes(block_shape, elem_bytes: int = ELEM_BYTES) -> int:
 
 
 def vmem_budget(platform: str = "tpu") -> int:
-    """Per-core VMEM budget in bytes for ``platform``."""
-    return VMEM_BUDGET_BYTES.get(platform, min(VMEM_BUDGET_BYTES.values()))
+    """Per-core VMEM budget in bytes for ``platform``.
+
+    An unknown platform raises: a kernel sized against a guessed budget
+    would fail, or run slowly, only once it reached that device.
+    """
+    if platform not in VMEM_BUDGET_BYTES:
+        raise KeyError(f"no VMEM budget for platform {platform!r}; have "
+                       f"{sorted(VMEM_BUDGET_BYTES)}")
+    return VMEM_BUDGET_BYTES[platform]
 
 
 def check_working_set(working_set_bytes: int, platform: str = "tpu") -> None:

@@ -52,7 +52,7 @@ def _flash_kernel(q_ref, posq_ref, k_hbm, v_hbm, sp_hbm, *rest, bc: int,
                                   k_sem.at[slot]),
             pltpu.make_async_copy(v_hbm.at[r, sl, :], v_scr.at[slot],
                                   v_sem.at[slot]),
-            pltpu.make_async_copy(sp_hbm.at[r, sl], sp_scr.at[slot],
+            pltpu.make_async_copy(sp_hbm.at[r, :, sl], sp_scr.at[slot],
                                   sp_sem.at[slot]),
         )
 
@@ -61,7 +61,7 @@ def _flash_kernel(q_ref, posq_ref, k_hbm, v_hbm, sp_hbm, *rest, bc: int,
             cp.start()
 
     q = q_ref[0]            # [Gp, hdp]
-    posq = posq_ref[r, 0]   # whole-array resident; one scalar per row
+    posq = posq_ref[r]      # whole vector in SMEM; one scalar per row
     gp, hdp = q.shape
 
     def step(c, carry):
@@ -78,13 +78,13 @@ def _flash_kernel(q_ref, posq_ref, k_hbm, v_hbm, sp_hbm, *rest, bc: int,
             cp.wait()
         kb = k_scr[slot]        # [bc, hdp]
         vb = v_scr[slot]
-        spb = sp_scr[slot]      # [bc]
+        spb = sp_scr[slot]      # [1, bc]
         s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         mask = spb <= posq
         if window:
             mask &= spb > posq - window
-        s = jnp.where(mask[None, :], s, -jnp.inf)
+        s = jnp.where(mask, s, -jnp.inf)
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.where(jnp.isfinite(m_new), jnp.exp(s - m_new), 0.0)
         corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_new), 0.0)
@@ -129,18 +129,20 @@ def flash_decode_pallas(
     cp = k.shape[1]
     nc = cp // bc
     has_lut = div_lut is not None
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     in_specs = [
         pl.BlockSpec((1, gp, hdp), lambda i: (i, 0, 0)),
-        pl.BlockSpec((r, 1), lambda i: (0, 0)),      # tiny: stays resident
+        pl.BlockSpec(memory_space=pltpu.SMEM),      # posq: scalar reads
         any_spec,                                    # k: manual DMA
         any_spec,                                    # v: manual DMA
         any_spec,                                    # slot positions
     ]
-    operands = [q, posq, k, v, sp]
+    # slot positions ride a unit sublane dim so each row's chunk DMA is
+    # a whole (1, bc) tile rather than a misaligned single-sublane slice
+    operands = [q, posq.reshape(-1), k, v, sp.reshape(r, 1, cp)]
     if has_lut:
-        in_specs.append(pl.BlockSpec((256,), lambda i: (0,)))
-        operands.append(div_lut)
+        in_specs.append(pl.BlockSpec((8, 256), lambda i: (0, 0)))
+        operands.append(fa.kernel_lut(div_lut))
     return pl.pallas_call(
         functools.partial(_flash_kernel, bc=bc, nc=nc, depth=depth,
                           window=window, floor=floor, has_lut=has_lut),
@@ -151,13 +153,12 @@ def flash_decode_pallas(
         scratch_shapes=[
             pltpu.VMEM((depth, bc, hdp), jnp.float32),
             pltpu.VMEM((depth, bc, hdp), jnp.float32),
-            pltpu.VMEM((depth, bc), jnp.int32),
+            pltpu.VMEM((depth, 1, bc), jnp.int32),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel",))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*operands)

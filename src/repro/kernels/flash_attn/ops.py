@@ -29,7 +29,7 @@ def _check_budget(bc: int, gp: int, hdp: int, depth: int) -> None:
     working = depth * (2 * budget.tile_bytes((bc, hdp))
                        + budget.tile_bytes((bc,)))
     working += 2 * budget.PIPELINE_BUFFERS * budget.tile_bytes((gp, hdp))
-    working += budget.tile_bytes((256,))
+    working += budget.tile_bytes(budget.LUT_TILE)
     budget.check_working_set(working)
 
 

@@ -131,9 +131,9 @@ def _rowwise_pipelined_call(tile_fn, x, lut, bm: int, depth: int,
     """pallas_call plumbing for the depth>=2 row-fused formulation."""
     m, npad = x.shape
     nslabs = m // bm
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    in_specs = [any_spec, pl.BlockSpec((256,), lambda i: (0,))]
-    operands = [x, lut]
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    in_specs = [any_spec, pl.BlockSpec((8, 256), lambda i: (0, 0))]
+    operands = [x, fa.kernel_lut(lut)]
     if b is not None:
         # the whole [M, 1] denominator column stays resident in VMEM
         # (4 bytes/row); slabs are sliced in-kernel
@@ -153,9 +153,8 @@ def _rowwise_pipelined_call(tile_fn, x, lut, bm: int, depth: int,
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("arbitrary",))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
 
@@ -172,15 +171,14 @@ def _rowwise_call(kernel, x, lut, bm: int, interpret: bool):
         grid=(m // bm,),
         in_specs=[
             pl.BlockSpec((bm, npad), lambda i: (i, 0)),
-            pl.BlockSpec((256,), lambda i: (0,)),
+            pl.BlockSpec((8, 256), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, npad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, npad), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel",))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, lut)
+    )(x, fa.kernel_lut(lut))
 
 
 @functools.partial(jax.jit,
@@ -224,15 +222,14 @@ def div_rowbcast_pallas(a, b, lut, *, bm: int = 8, depth: int = 1,
         in_specs=[
             pl.BlockSpec((bm, npad), lambda i: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-            pl.BlockSpec((256,), lambda i: (0,)),
+            pl.BlockSpec((8, 256), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, npad), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, npad), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel",))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(a, b, lut)
+    )(a, b, fa.kernel_lut(lut))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -246,12 +243,11 @@ def div_pallas(a, b, lut, *, block=(8, 128), interpret: bool = False):
         in_specs=[
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((256,), lambda i, j: (0,)),
+            pl.BlockSpec((8, 256), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, c), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(a, b, lut)
+    )(a, b, fa.kernel_lut(lut))

@@ -42,7 +42,7 @@ def _check_budget(bm: int, npad: int, depth: int) -> None:
     buffers = depth if depth >= 2 else budget.PIPELINE_BUFFERS
     budget.check_working_set(
         2 * buffers * budget.tile_bytes((bm, npad))
-        + budget.tile_bytes((256,)))
+        + budget.tile_bytes(budget.LUT_TILE))
 
 
 def _resolve(spec, interpret):

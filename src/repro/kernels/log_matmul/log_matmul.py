@@ -55,7 +55,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import float_approx as fa
 from repro.core.backend import Epilogue, apply_epilogue_tile
+from repro.kernels.budget import LANE
 
 F32_BIAS = 127 << 23
 F32_ABS = 0x7FFFFFFF
@@ -76,7 +78,7 @@ def _approx_prod(bx_col: jnp.ndarray, bw_row: jnp.ndarray, lut: jnp.ndarray):
     m2 = bw_row & F32_ABS
     i1 = (m1 >> 19) & 0xF
     i2 = (m2 >> 19) & 0xF
-    c = lut[(i1 * 16 + i2).astype(jnp.int32)]
+    c = fa.lut_take(lut, (i1 * 16 + i2).astype(jnp.int32))
     s = m1 + m2 - F32_BIAS + c
     s = jnp.where(s < MIN_NORMAL, 0, s)
     s = jnp.where(s >= INF_BITS, INF_BITS, s)
@@ -101,10 +103,9 @@ def _kernel(x_ref, w_ref, lut_ref, *rest, bk: int, unroll: int, nk: int,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    bx = jax.lax.bitcast_convert_type(x_ref[...], jnp.int32)  # [bm, bk]
-    bw = jax.lax.bitcast_convert_type(w_ref[...], jnp.int32)  # [bk, bn]
-    o_ref[...] += _accumulate_block(bx, bw, lut_ref[...], o_ref[...],
-                                    bk, unroll)
+    bm, bn = o_ref.shape
+    o_ref[...] += _accumulate_block(x_ref, w_ref, lut_ref[...], bm, bn, bk,
+                                    unroll)
 
     if has_bias or has_residual or not ep.is_identity:
         # epilogue menu on the tile's final K visit, while it sits in
@@ -123,18 +124,38 @@ def _kernel(x_ref, w_ref, lut_ref, *rest, bk: int, unroll: int, nk: int,
                 o_ref[...] = out
 
 
-def _accumulate_block(bx, bw, lut, out_like, bk: int, unroll: int):
+def _accumulate_block(x_ref, w_ref, lut, bm: int, bn: int, bk: int,
+                      unroll: int):
     """Zeros + sum of rank-1 slabs over one K block (the canonical
-    accumulation order both formulations and the chunk=1 scan share)."""
+    accumulation order both formulations and the chunk=1 scan share).
+
+    ``x_ref`` / ``w_ref`` are the block's [bm, bk] / [bk, bn] VMEM refs.
+    Row ``kk`` of w is a dynamic single-row load; column ``kk`` of x is
+    picked out of its aligned lane chunk by a one-hot integer lane sum,
+    which returns the operand bits exactly (Mosaic has no dynamic
+    lane-offset slice).
+    """
+    width = min(bk, LANE)
+    if bk % width:
+        raise ValueError(f"bk={bk} must be below {LANE} or a multiple of it")
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, width), 1)
 
     def body(t, acc):
+        k0 = t * unroll
+        start = pl.multiple_of(k0 // width * width, width)
+        xb = jax.lax.bitcast_convert_type(x_ref[:, pl.ds(start, width)],
+                                          jnp.int32)
         for u in range(unroll):
-            kk = t * unroll + u
-            acc = acc + _approx_prod(bx[:, kk][:, None], bw[kk, :][None, :],
-                                     lut)
+            kk = k0 + u
+            bx = jnp.sum(jnp.where(lane == kk - start, xb, 0), axis=1,
+                         keepdims=True)                          # [bm, 1]
+            bw = jax.lax.bitcast_convert_type(w_ref[pl.ds(kk, 1), :],
+                                              jnp.int32)         # [1, bn]
+            acc = acc + _approx_prod(bx, bw, lut)
         return acc
 
-    return jax.lax.fori_loop(0, bk // unroll, body, jnp.zeros_like(out_like))
+    return jax.lax.fori_loop(0, bk // unroll, body,
+                             jnp.zeros((bm, bn), jnp.float32))
 
 
 def _pipelined_kernel(x_hbm, w_hbm, lut_ref, *rest, bm: int, bn: int,
@@ -193,9 +214,8 @@ def _pipelined_kernel(x_hbm, w_hbm, lut_ref, *rest, bm: int, bn: int,
 
         x_dma(slot, kk).wait()
         w_dma(slot, kk).wait()
-        bx = jax.lax.bitcast_convert_type(x_scr[slot], jnp.int32)
-        bw = jax.lax.bitcast_convert_type(w_scr[slot], jnp.int32)
-        return acc + _accumulate_block(bx, bw, lut, acc, bk, unroll)
+        return acc + _accumulate_block(x_scr.at[slot], w_scr.at[slot], lut,
+                                       bm, bn, bk, unroll)
 
     acc = jax.lax.fori_loop(
         0, nk, k_step, jnp.zeros((bm, bn), jnp.float32))
@@ -252,13 +272,13 @@ def log_matmul_pipelined(
     nk = k // bk
     has_bias = bias is not None
     has_residual = residual is not None
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     in_specs = [
         any_spec,                                    # x: manual DMA
         any_spec,                                    # w: manual DMA
-        pl.BlockSpec((256,), lambda i, j: (0,)),     # mul LUT
+        pl.BlockSpec((8, 256), lambda i, j: (0, 0)), # mul LUT
     ]
-    operands = [x, w, lut]
+    operands = [x, w, fa.kernel_lut(lut)]
     if has_bias:
         in_specs.append(pl.BlockSpec((bn,), lambda i, j: (j,)))
         operands.append(bias)
@@ -266,8 +286,8 @@ def log_matmul_pipelined(
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j: (i, j)))
         operands.append(residual)
     if epilogue.wants_norm_lut:
-        in_specs.append(pl.BlockSpec((256,), lambda i, j: (0,)))
-        operands.append(div_lut)
+        in_specs.append(pl.BlockSpec((8, 256), lambda i, j: (0, 0)))
+        operands.append(fa.kernel_lut(div_lut))
     out_spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     out_shape = jax.ShapeDtypeStruct((m, npad), jnp.float32)
     if epilogue.keep_prenorm:
@@ -288,9 +308,8 @@ def log_matmul_pipelined(
             pltpu.SemaphoreType.DMA((depth,)),
             pltpu.SemaphoreType.DMA((depth,)),
         ],
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*operands)
 
@@ -340,9 +359,9 @@ def log_matmul_pallas(
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((256,), lambda i, j, kk: (0,)),
+        pl.BlockSpec((8, 256), lambda i, j, kk: (0, 0)),
     ]
-    operands = [x, w, lut]
+    operands = [x, w, fa.kernel_lut(lut)]
     if has_bias:
         in_specs.append(pl.BlockSpec((bn,), lambda i, j, kk: (j,)))
         operands.append(bias)
@@ -350,8 +369,8 @@ def log_matmul_pallas(
         in_specs.append(pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)))
         operands.append(residual)
     if epilogue.wants_norm_lut:
-        in_specs.append(pl.BlockSpec((256,), lambda i, j, kk: (0,)))
-        operands.append(div_lut)
+        in_specs.append(pl.BlockSpec((8, 256), lambda i, j, kk: (0, 0)))
+        operands.append(fa.kernel_lut(div_lut))
     out_spec = pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j))
     out_shape = jax.ShapeDtypeStruct((m, npad), jnp.float32)
     if epilogue.keep_prenorm:
@@ -366,8 +385,7 @@ def log_matmul_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

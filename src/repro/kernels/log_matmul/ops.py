@@ -33,11 +33,11 @@ def _check_budget(bm: int, bn: int, bk: int, ep: Epilogue,
     row_tiles = 1 + has_residual + ep.keep_prenorm       # out, res, pre
     working += budget.PIPELINE_BUFFERS * row_tiles * budget.tile_bytes(
         (bm, bn))
-    working += budget.tile_bytes((256,))                 # mul LUT
+    working += budget.tile_bytes(budget.LUT_TILE)        # mul LUT
     if has_bias:
         working += budget.PIPELINE_BUFFERS * budget.tile_bytes((bn,))
     if ep.wants_norm_lut:
-        working += budget.tile_bytes((256,))
+        working += budget.tile_bytes(budget.LUT_TILE)
     budget.check_working_set(working)
 
 

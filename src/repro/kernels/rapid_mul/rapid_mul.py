@@ -14,7 +14,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import float_approx as fa
 from repro.core.bitops import ilog2
 
 
@@ -30,7 +32,7 @@ def _kernel(a_ref, b_ref, lut_ref, o_ref, *, n_bits: int):
     f2 = (b - (jnp.int32(1) << k2)) << (F - k2)
     i1 = (f1 >> (F - 4)) & 0xF
     i2 = (f2 >> (F - 4)) & 0xF
-    c = lut[(i1 * 16 + i2).astype(jnp.int32)]
+    c = fa.lut_take(lut, (i1 * 16 + i2).astype(jnp.int32))
 
     s = f1 + f2 + c
     one = jnp.int32(1) << F
@@ -65,12 +67,11 @@ def rapid_mul_pallas(
         in_specs=[
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((256,), lambda i, j: (0,)),
+            pl.BlockSpec((8, 256), lambda i, j: (0, 0)),
         ],
         out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, ccols), jnp.uint32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(a, b, lut)
+    )(a, b, fa.kernel_lut(lut))

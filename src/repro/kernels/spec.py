@@ -283,10 +283,8 @@ def resolve_spec(
 def _cache_lookup(family, shapes, *, scheme, epilogue, platform):
     """Consult the committed tuning cache (lazy import: no cycle, and
     spec construction stays importable without jax)."""
-    try:
-        from repro.kernels import autotune
-    except Exception:  # pragma: no cover - autotune must not be load-bearing
-        return None
+    from repro.kernels import autotune
+
     return autotune.cached_spec(family, shapes, scheme=scheme,
                                 epilogue_kind=epilogue_kind(epilogue),
                                 platform=platform)

@@ -13,6 +13,7 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ARCH_IDS, RAPID, get_config
 from repro.launch.backend_args import add_backend_args, apply_backend_args
 from repro.models.layers import ParallelCtx
@@ -35,6 +36,7 @@ def main():
                          "+ slot recycling (repro.serve.scheduler)")
     add_backend_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

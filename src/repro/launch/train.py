@@ -11,6 +11,7 @@ import argparse
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ARCH_IDS, RAPID, get_config
 from repro.data.pipeline import SyntheticLM
 from repro.launch.backend_args import add_backend_args, apply_backend_args
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,7 +1,6 @@
-"""The jax-version compat shim: both shard_map signatures, monkeypatched,
+"""The compat shim: the shard_map adapter over monkeypatched signatures,
 plus the real resolution on the installed jax; the manual-mesh (axis-env)
-helpers against both API generations (legacy frame stack vs modern
-AxisEnv) and against the real shard_map."""
+helpers against a fake AxisEnv and against the real shard_map."""
 import inspect
 from types import SimpleNamespace
 
@@ -23,28 +22,11 @@ def _capture(calls):
     return run
 
 
-def test_adapt_maps_check_vma_to_check_rep():
-    calls = []
-    run = _capture(calls)
-
-    def legacy(f, *, mesh, in_specs, out_specs, check_rep=True):  # jax 0.4.x
-        return run(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_rep)
-
-    wrapped = compat.adapt_shard_map(legacy)
-    body = lambda x: x  # noqa: E731
-    out = wrapped(body, mesh="MESH", in_specs=("i",), out_specs="o",
-                  check_vma=False)
-    assert out is body
-    assert calls == [{"mesh": "MESH", "in_specs": ("i",), "out_specs": "o",
-                      "check_rep": False}]
-
-
 def test_adapt_passes_check_vma_through_on_modern_jax():
     calls = []
     run = _capture(calls)
 
-    def modern(f, *, mesh, in_specs, out_specs, check_vma=True):  # jax 0.8+
+    def modern(f, *, mesh, in_specs, out_specs, check_vma=True):
         return run(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                    check_vma=check_vma)
 
@@ -69,10 +51,10 @@ def test_adapt_omits_flag_when_unset():
     calls = []
     run = _capture(calls)
 
-    def legacy(f, *, mesh, in_specs, out_specs, **kw):
+    def modern(f, *, mesh, in_specs, out_specs, **kw):
         return run(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
-    wrapped = compat.adapt_shard_map(legacy)
+    wrapped = compat.adapt_shard_map(modern)
     wrapped(lambda x: x, mesh="M", in_specs="i", out_specs="o")
     assert "check_rep" not in calls[0] and "check_vma" not in calls[0]
 
@@ -81,8 +63,8 @@ def test_resolve_finds_installed_shard_map():
     impl = compat.resolve_shard_map()
     assert callable(impl)
     params = inspect.signature(impl).parameters
-    # whichever jax this is, the shim must know its check kwarg (or lack)
-    assert compat._check_kwarg_name(impl) in ("check_vma", "check_rep", None)
+    assert "check_vma" in params
+    assert compat._check_kwarg_name(impl) == "check_vma"
 
 
 def test_shard_map_executes_on_installed_jax():
@@ -102,64 +84,27 @@ def test_shard_map_executes_on_installed_jax():
 
 
 # --------------------------------------------------------------------------
-# manual-mesh (axis-env) helpers: both API generations, one contract
+# manual-mesh (axis-env) helpers
 # --------------------------------------------------------------------------
 
-class _Frame(SimpleNamespace):
-    """Legacy AxisEnvFrame stand-in (jax <= 0.4.35): .name / .size."""
-
-
-def _legacy_core(frames):
-    """A jax.core lookalike exposing only the legacy frame-stack surface."""
-    return SimpleNamespace(
-        thread_local_state=SimpleNamespace(
-            trace_state=SimpleNamespace(axis_env=frames)))
-
-
 def _modern_core(axis_sizes):
-    """A jax.core lookalike exposing only the 0.4.36+/0.8+ get_axis_env."""
+    """A jax.core lookalike exposing get_axis_env."""
     env = SimpleNamespace(axis_sizes=axis_sizes, spmd_axis_names=set())
     return SimpleNamespace(get_axis_env=lambda: env)
 
 
 def test_axis_sizes_pure_helpers_both_generations():
-    frames = [_Frame(name="data", size=4), _Frame(name="model", size=2)]
-    legacy = compat.axis_sizes_from_frames(frames)
     modern = compat.axis_sizes_from_env(
         SimpleNamespace(axis_sizes={"data": 4, "model": 2}))
-    assert legacy == modern == {"data": 4, "model": 2}
-    # empty environments read as "not in a manual region" in both shapes
-    assert compat.axis_sizes_from_frames([]) == {}
+    assert modern == {"data": 4, "model": 2}
+    # empty environments read as "not in a manual region"
     assert compat.axis_sizes_from_env(SimpleNamespace(axis_sizes={})) == {}
     assert compat.axis_sizes_from_env(SimpleNamespace()) == {}
 
 
-def test_axis_sizes_from_frames_skips_unnamed_axes():
-    """The no_axis_name sentinel an unnamed vmap pushes is not a manual
-    mesh axis and must not count as shard_map evidence."""
-    sentinel = object()  # stands in for jax.core.no_axis_name
-    frames = [_Frame(name=sentinel, size=3), _Frame(name="model", size=2),
-              _Frame(name="dropme", size=None)]
-    assert compat.axis_sizes_from_frames(frames) == {"model": 2}
-
-
-def test_axis_env_reader_identical_across_api_generations():
-    """The resolved reader behaves identically whether the core exposes
-    the 0.4.x frame stack or the 0.8+ AxisEnv — same sizes, same
-    in-region verdict, same local-axis products."""
-    sizes = {"data": 4, "model": 2}
-    legacy_reader = compat.axis_env_reader_for(
-        _legacy_core([_Frame(name=n, size=s) for n, s in sizes.items()]))
-    modern_reader = compat.axis_env_reader_for(_modern_core(dict(sizes)))
-    assert legacy_reader() == modern_reader() == sizes
-    # a core exposing neither surface: never inside a manual region
-    assert compat.axis_env_reader_for(SimpleNamespace())() == {}
-
-
 def test_manual_helpers_through_monkeypatched_modern_core(monkeypatch):
-    """axis_env_sizes() reads through jax.core when it exposes the
-    modern surface (the 0.8+ shape, exercised on whatever jax is
-    installed)."""
+    """axis_env_sizes() reads through jax.core when it exposes
+    get_axis_env."""
     env = SimpleNamespace(axis_sizes={"model": 8}, spmd_axis_names=set())
     monkeypatch.setattr(jax.core, "get_axis_env", lambda: env,
                         raising=False)
@@ -171,8 +116,7 @@ def test_manual_helpers_through_monkeypatched_modern_core(monkeypatch):
 
 
 def test_manual_axis_size_products():
-    frames = [_Frame(name="data", size=4), _Frame(name="model", size=2)]
-    reader = compat.axis_env_reader_for(_legacy_core(frames))
+    reader = compat.axis_env_reader_for(_modern_core({"data": 4, "model": 2}))
     # product semantics via the pure reader feeding a fake jax.core
     sizes = reader()
     assert sizes["data"] * sizes["model"] == 8

@@ -47,7 +47,7 @@ _SUBPROC = textwrap.dedent("""
     from repro.train.optimizer import OptConfig
     from repro.train.trainstep import make_train_step
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(data=4, model=2)
     cfg = get_config("qwen3_moe_235b_a22b").reduced().with_(
         n_experts=4, experts_per_token=2, d_model=64, d_ff=32,
         vocab_size=512, scan_layers=True, n_layers=2)

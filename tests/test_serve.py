@@ -139,15 +139,24 @@ def test_engine_overflow_raises_value_error():
         eng.generate([[1] * 12], max_new=8)
 
 
+def _first_new_token(tokens):
+    """First index > 0 whose token does not occur earlier in ``tokens``."""
+    return next(i for i in range(1, len(tokens))
+                if tokens[i] not in tokens[:i])
+
+
 def test_engine_stop_token_not_emitted():
     """Stop-token semantics: terminate the request *without* emitting."""
     cfg, m, params = _setup("minicpm_2b", f32=True)
     eng = ServeEngine(m, params, CTX, cache_n=64)
     free = eng.generate([[1, 2, 3]], max_new=6)[0]
     assert len(free) == 6
-    stop = free[3]
+    # the first position whose token did not occur before it: an earlier
+    # occurrence would (rightly) stop the request sooner
+    i = _first_new_token(free)
+    stop = free[i]
     out = eng.generate([[1, 2, 3]], max_new=6, stop_token=stop)[0]
-    assert out == free[:3] and stop not in out
+    assert out == free[:i] and stop not in out
     # stop on the very first sampled token -> empty output
     out0 = eng.generate([[1, 2, 3]], max_new=6, stop_token=free[0])[0]
     assert out0 == []
@@ -238,8 +247,9 @@ def test_continuous_stop_token_and_max_new_edges():
     free = eng.generate([[1, 2, 3]], max_new=6)[0]
     assert len(free) == 6
     # stop token terminates without being emitted
-    out = eng.generate([[1, 2, 3]], max_new=6, stop_token=free[2])[0]
-    assert out == free[:2] and free[2] not in out
+    i = _first_new_token(free)
+    out = eng.generate([[1, 2, 3]], max_new=6, stop_token=free[i])[0]
+    assert out == free[:i] and free[i] not in out
     # stop on the first sampled token -> empty output, done event only
     evs = list(eng.stream([[1, 2, 3]], max_new=6, stop_token=free[0]))
     assert [e.token for e in evs] == [None] and evs[-1].done

@@ -31,6 +31,7 @@ from repro.models import moe
 from repro.models.layers import ParallelCtx
 from repro.models.moe import moe_ffn, moe_params
 from repro.models.params import materialize
+from repro.launch.mesh import make_local_mesh
 from repro.parallel.sharding import make_rules
 
 NDEV = 8
@@ -102,7 +103,7 @@ def test_moe_sharded_kernel_bitexact_vs_jnp_oracle(mesh_shape, rules_kw,
     params, x = _moe_inputs(cfg)
     oracle = _jnp_oracle(cfg, params, x, monkeypatch)
 
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_local_mesh(*mesh_shape)
     ctx = ParallelCtx(mesh, make_rules(cfg, **rules_kw))
     out = jax.jit(lambda x, p: moe_ffn(x, p, cfg, ctx))(x, params)
     np.testing.assert_array_equal(
@@ -118,7 +119,7 @@ def test_moe_fsdp_token_gather_matches_oracle_to_f32_tolerance(monkeypatch):
     params, x = _moe_inputs(cfg)
     oracle = _jnp_oracle(cfg, params, x, monkeypatch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_local_mesh(data=4, model=2)
     ctx = ParallelCtx(mesh, make_rules(cfg, fsdp=True, seq_parallel=False))
     out = jax.jit(lambda x, p: moe_ffn(x, p, cfg, ctx))(x, params)
     np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
@@ -144,7 +145,7 @@ def test_tp_matmul_under_shard_map_bitexact(m, n, k):
     w = jnp.asarray(rng.normal(size=(k, n)), jnp.float32)
     b = jnp.asarray(rng.normal(size=(n,)), jnp.float32)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_local_mesh(data=2, model=4)
 
     def body(xl, wl, bl):
         return qmatmul(xl, wl, "rapid10", backend="pallas-interpret",
@@ -181,7 +182,7 @@ def test_flash_decode_combine_runs_in_body_and_matches_unsharded():
 
     ref = decode_attention(q, kc, vc, sp, C - 1, 0, acfg)
 
-    mesh = jax.make_mesh((1, NDEV), ("data", "model"))
+    mesh = make_local_mesh(data=1, model=NDEV)
     ctx = ParallelCtx(mesh, make_rules(None, shard_batch=False,
                                        shard_cache_seq=True))
     sharded = jax.jit(lambda q, kc, vc, sp: decode_attention(
@@ -212,7 +213,7 @@ def test_auto_hw_pin_routes_kernels_only_inside_manual_regions(monkeypatch):
     assert pinned.approx.backend_for("mlp") == be.AUTO_HW
     params, x = _moe_inputs(pinned)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_local_mesh(data=2, model=4)
     ctx = ParallelCtx(mesh, make_rules(pinned, fsdp=False,
                                        seq_parallel=False))
     sharded = str(jax.make_jaxpr(
@@ -233,6 +234,7 @@ _SUBPROC = textwrap.dedent("""
     from repro.models.layers import ParallelCtx
     from repro.models.moe import moe_ffn, moe_params
     from repro.models.params import materialize
+    from repro.launch.mesh import make_local_mesh
     from repro.parallel.sharding import make_rules
     from repro.core.ops import qmatmul_batched
 
@@ -250,7 +252,7 @@ _SUBPROC = textwrap.dedent("""
     oracle = moe_ffn(x, params, cfg.with_backend("jnp"), ParallelCtx())
     moe.qmatmul_batched = qmatmul_batched
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_local_mesh(data=2, model=4)
     ctx = ParallelCtx(mesh, make_rules(cfg, fsdp=False, seq_parallel=False))
     out = jax.jit(lambda x, p: moe_ffn(x, p, cfg, ctx))(x, params)
     assert np.array_equal(np.asarray(out).view(np.int32),
