@@ -1,0 +1,13 @@
+"""95th percentile, over the requests due in the window, of admission
+into a slot minus submission, in ms, on the engine's clock; a request
+still queued at the close counts what it has waited (``bench/spans.py``).
+None where the run's context holds no request times."""
+from bench import spans, stats
+
+
+def read(ctx):
+    times = getattr(ctx, "request_times", None)
+    if times is None:
+        return None
+    v = spans.waits(ctx.window, times, "submitted", "admitted")
+    return 1e3 * stats.percentile(v, 95) if v else None

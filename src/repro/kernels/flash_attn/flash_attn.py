@@ -161,4 +161,5 @@ def flash_decode_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="flash_decode_pallas",
     )(*operands)

@@ -127,8 +127,9 @@ def _rowwise_pipelined_kernel(x_hbm, lut_ref, *rest, tile_fn, bm: int,
 
 
 def _rowwise_pipelined_call(tile_fn, x, lut, bm: int, depth: int,
-                            interpret: bool, b=None):
-    """pallas_call plumbing for the depth>=2 row-fused formulation."""
+                            interpret: bool, name: str, b=None):
+    """pallas_call plumbing for the depth>=2 row-fused formulation;
+    ``name`` is the kernel's name in a device trace."""
     m, npad = x.shape
     nslabs = m // bm
     any_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
@@ -156,11 +157,13 @@ def _rowwise_pipelined_call(tile_fn, x, lut, bm: int, depth: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
-def _rowwise_call(kernel, x, lut, bm: int, interpret: bool):
-    """Shared pallas_call plumbing for the row-fused kernels.
+def _rowwise_call(kernel, x, lut, bm: int, interpret: bool, name: str):
+    """Shared pallas_call plumbing for the row-fused kernels, named
+    ``name`` in a device trace.
 
     x: [M, n_pad] f32 with M % bm == 0 and n_pad % LANE == 0; every grid
     step owns bm full rows (the whole reduction axis stays in VMEM).
@@ -178,6 +181,7 @@ def _rowwise_call(kernel, x, lut, bm: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name=name,
     )(x, fa.kernel_lut(lut))
 
 
@@ -190,9 +194,9 @@ def softmax_div_pallas(e, lut, *, floor: float = ref.SOFTMAX_FLOOR,
     if depth >= 2:
         return _rowwise_pipelined_call(
             functools.partial(_softmax_tile, floor=floor),
-            e, lut, bm, depth, interpret)
+            e, lut, bm, depth, interpret, "softmax_div_pallas")
     return _rowwise_call(functools.partial(_softmax_kernel, floor=floor),
-                         e, lut, bm, interpret)
+                         e, lut, bm, interpret, "softmax_div_pallas")
 
 
 @functools.partial(jax.jit,
@@ -203,9 +207,9 @@ def rms_div_pallas(x, lut, *, n: int, eps: float, bm: int = 8,
     if depth >= 2:
         return _rowwise_pipelined_call(
             functools.partial(_rms_tile, n=n, eps=eps),
-            x, lut, bm, depth, interpret)
+            x, lut, bm, depth, interpret, "rms_div_pallas")
     return _rowwise_call(functools.partial(_rms_kernel, n=n, eps=eps),
-                         x, lut, bm, interpret)
+                         x, lut, bm, interpret, "rms_div_pallas")
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "depth", "interpret"))
@@ -215,7 +219,8 @@ def div_rowbcast_pallas(a, b, lut, *, bm: int = 8, depth: int = 1,
     m, npad = a.shape
     if depth >= 2:
         return _rowwise_pipelined_call(
-            fa.log_div_f32, a, lut, bm, depth, interpret, b=b)
+            fa.log_div_f32, a, lut, bm, depth, interpret,
+            "div_rowbcast_pallas", b=b)
     return pl.pallas_call(
         _div_rowbcast_kernel,
         grid=(m // bm,),
@@ -229,6 +234,7 @@ def div_rowbcast_pallas(a, b, lut, *, bm: int = 8, depth: int = 1,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="div_rowbcast_pallas",
     )(a, b, fa.kernel_lut(lut))
 
 
@@ -250,4 +256,5 @@ def div_pallas(a, b, lut, *, block=(8, 128), interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="div_pallas",
     )(a, b, fa.kernel_lut(lut))

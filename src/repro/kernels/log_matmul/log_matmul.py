@@ -311,6 +311,7 @@ def log_matmul_pipelined(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="log_matmul_pipelined",
     )(*operands)
 
 
@@ -388,4 +389,5 @@ def log_matmul_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="log_matmul_pallas",
     )(*operands)

@@ -75,4 +75,5 @@ def rapid_div_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="rapid_div_pallas",
     )(a, b, fa.kernel_lut(lut))

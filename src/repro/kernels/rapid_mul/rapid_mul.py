@@ -74,4 +74,5 @@ def rapid_mul_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="rapid_mul_pallas",
     )(a, b, fa.kernel_lut(lut))

@@ -181,34 +181,38 @@ def block_decode_paged(x, p, cache, page_table, positions, valid,
     q = rope(q, positions, cfg.rope_theta)
     k = rope(k, positions, cfg.rope_theta)
 
-    # page-table indirection write; invalid tokens go to the scratch
-    # page (0), whose contents are never addressed by any page table
-    # audit: exact — integer page-index arithmetic, not datapath
-    pidx = jnp.clip(positions // PS, 0, Pp - 1)           # [B, S]
-    pid = jnp.take_along_axis(page_table, pidx, axis=1)   # [B, S]
-    pid = jnp.where(valid, pid, 0).reshape(-1)
-    poff = (positions % PS).reshape(-1)
-    ck = cache["k"].at[pid, poff].set(
-        k.reshape(B * S, KV, hd).astype(cache["k"].dtype))
-    cv = cache["v"].at[pid, poff].set(
-        v.reshape(B * S, KV, hd).astype(cache["v"].dtype))
+    # the scopes name these ops in the compiled program's metadata, so a
+    # device trace can put the paged KV path's time down to it
+    with jax.named_scope("paged_kv"):
+        # page-table indirection write; invalid tokens go to the scratch
+        # page (0), whose contents are never addressed by any page table
+        # audit: exact — integer page-index arithmetic, not datapath
+        pidx = jnp.clip(positions // PS, 0, Pp - 1)           # [B, S]
+        pid = jnp.take_along_axis(page_table, pidx, axis=1)   # [B, S]
+        pid = jnp.where(valid, pid, 0).reshape(-1)
+        poff = (positions % PS).reshape(-1)
+        ck = cache["k"].at[pid, poff].set(
+            k.reshape(B * S, KV, hd).astype(cache["k"].dtype))
+        cv = cache["v"].at[pid, poff].set(
+            v.reshape(B * S, KV, hd).astype(cache["v"].dtype))
 
-    # gather the slot views back through the table: [B, P*PS, KV, hd]
-    kg = ck[page_table].reshape(B, C, KV, hd)
-    vg = cv[page_table].reshape(B, C, KV, hd)
-    j = jnp.arange(C, dtype=jnp.int32)
-    kv_pos = jnp.where(j[None, :] < kv_len[:, None], j[None, :],
-                       jnp.iinfo(jnp.int32).max)          # [B, C]
+        # gather the slot views back through the table: [B, P*PS, KV, hd]
+        kg = ck[page_table].reshape(B, C, KV, hd)
+        vg = cv[page_table].reshape(B, C, KV, hd)
+        j = jnp.arange(C, dtype=jnp.int32)
+        kv_pos = jnp.where(j[None, :] < kv_len[:, None], j[None, :],
+                           jnp.iinfo(jnp.int32).max)          # [B, C]
 
-    if S == 1:
-        # the hot path: same formulation as the dense decode step, so a
-        # paged slot's logits are bit-identical to a lockstep slot's
-        attn_out = decode_attention(
-            q[:, 0], kg, vg, kv_pos, positions[:, 0], cfg.sliding_window,
-            acfg, ctx)[:, None]
-    else:
-        attn_out = chunk_cache_attention(
-            q, kg, vg, positions, kv_pos, cfg.sliding_window, acfg)
+    with jax.named_scope("attention"):
+        if S == 1:
+            # the hot path: same formulation as the dense decode step, so
+            # a paged slot's logits are bit-identical to a lockstep slot's
+            attn_out = decode_attention(
+                q[:, 0], kg, vg, kv_pos, positions[:, 0],
+                cfg.sliding_window, acfg, ctx)[:, None]
+        else:
+            attn_out = chunk_cache_attention(
+                q, kg, vg, positions, kv_pos, cfg.sliding_window, acfg)
     x = dense(attn_out, p["attn"]["wo"], acfg, "attn_proj",
               residual=x)
     h2 = apply_norm(x, p["ln2"], cfg, norm_kind)

@@ -26,23 +26,38 @@ has fixed ``[1, prefill_chunk]`` shapes, so it too compiles once.
 Sampling keys derive per request: ``fold_in(fold_in(root, rid), k)`` for
 a request's k-th draw — a request's sampled tokens are deterministic
 and independent of which requests happen to co-reside in the batch.
+
+Tracing costs nothing unless a profiler is collecting.  The two programs
+are ``jit_decode_tick`` and ``jit_prefill_chunk`` in a device trace;
+each phase of :meth:`ContinuousServeEngine.step` runs inside one
+``jax.profiler.TraceAnnotation`` (``serve.admit``, ``serve.prefill``,
+``serve.decode``, ``serve.fetch``, ``serve.sample``), whose enabled
+check is the profiler's own.  Each request is stamped on the engine's
+clock when it is submitted, admitted, and when its first prefill chunk
+is dispatched (:class:`RequestTimes`, a bounded record).
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Iterator, List, Optional
+from typing import Callable, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import backend as be
 from repro.models.layers import ParallelCtx
 from repro.models.model import Model
 from repro.serve.paged_kv import PageAllocator, PageGeometry
 
-__all__ = ["StreamEvent", "ContinuousServeEngine"]
+__all__ = ["StreamEvent", "RequestTimes", "ContinuousServeEngine",
+           "TIMES_KEPT"]
+
+#: how many requests' :class:`RequestTimes` an engine keeps, newest last
+TIMES_KEPT = 4096
 
 
 @dataclass(frozen=True)
@@ -59,11 +74,24 @@ class StreamEvent:
 
 
 @dataclass
+class RequestTimes:
+    """When a request was submitted, admitted into a slot, and had its
+    first prefill chunk dispatched, on the engine's clock; None until
+    it happens."""
+
+    rid: int
+    submitted: float
+    admitted: Optional[float] = None
+    first_chunk: Optional[float] = None
+
+
+@dataclass
 class _Queued:
     rid: int
     prompt: List[int]
     max_new: int
     stop_token: Optional[int]
+    times: RequestTimes
 
 
 @dataclass
@@ -73,6 +101,7 @@ class _Slot:
     max_new: int
     stop_token: Optional[int]
     pages: List[int]
+    times: RequestTimes
     n_prefilled: int = 0
     length: int = 0              # KV tokens stored for this slot
     last_token: Optional[int] = None   # pending token to feed to decode
@@ -89,6 +118,8 @@ class ContinuousServeEngine:
     shared pool (default: every slot can be full simultaneously — the
     same peak KV memory as a fixed-slot engine with ``cache_n ==
     max_len``, but shorter requests leave their pages to others).
+    ``clock`` stamps :attr:`request_times`, the waits of the last
+    :data:`TIMES_KEPT` requests submitted.
     """
 
     def __init__(self, model: Model, params: dict,
@@ -96,7 +127,8 @@ class ContinuousServeEngine:
                  max_len: int = 256, page_size: int = 16,
                  n_pages: Optional[int] = None, prefill_chunk: int = 16,
                  temperature: float = 0.0, seed: int = 0,
-                 backend: Optional[str] = None):
+                 backend: Optional[str] = None,
+                 clock: Callable[[], float] = time.perf_counter):
         if model.cfg.family not in ("dense", "moe"):
             raise ValueError(
                 "continuous batching serves decoder-only text families "
@@ -112,6 +144,7 @@ class ContinuousServeEngine:
         self.prefill_chunk = prefill_chunk
         self.temperature = temperature
         self.seed = seed
+        self.clock = clock
 
         pages_per_slot = -(-max_len // page_size)
         if n_pages is None:
@@ -125,23 +158,22 @@ class ContinuousServeEngine:
         self._slots: List[Optional[_Slot]] = [None] * n_slots
         self._next_rid = 0
         self._root_key = jax.random.PRNGKey(seed)
+        self.request_times: deque = deque(maxlen=TIMES_KEPT)
         # trace-time counters: each jit retrace == one compile, so the
         # bench gate can assert "decode recompiles at most once"
         self.trace_counts = {"decode": 0, "prefill": 0}
 
-        def _count(name):
-            self.trace_counts[name] += 1
+        # named, so that a device trace tells the two programs apart
+        def decode_tick(p, c, t, pt, off, nv):
+            self.trace_counts["decode"] += 1
+            return self.model.decode_paged(p, t, c, pt, off, nv, self.ctx)
 
-        self._decode = jax.jit(
-            lambda p, c, t, pt, off, nv: (
-                _count("decode"),
-                self.model.decode_paged(p, t, c, pt, off, nv, self.ctx),
-            )[1])
-        self._prefill = jax.jit(
-            lambda p, c, t, pt, off, nv: (
-                _count("prefill"),
-                self.model.decode_paged(p, t, c, pt, off, nv, self.ctx),
-            )[1])
+        def prefill_chunk(p, c, t, pt, off, nv):
+            self.trace_counts["prefill"] += 1
+            return self.model.decode_paged(p, t, c, pt, off, nv, self.ctx)
+
+        self._decode = jax.jit(decode_tick)
+        self._prefill = jax.jit(prefill_chunk)
 
     # ------------------------------------------------------------------
     # request lifecycle
@@ -166,7 +198,10 @@ class ContinuousServeEngine:
                 f"has only {self.geom.usable_pages} usable pages")
         rid = self._next_rid
         self._next_rid += 1
-        self._queue.append(_Queued(rid, list(prompt), max_new, stop_token))
+        times = RequestTimes(rid, self.clock())
+        self.request_times.append(times)
+        self._queue.append(_Queued(rid, list(prompt), max_new, stop_token,
+                                   times))
         return rid
 
     def cancel(self, rid: int) -> bool:
@@ -209,8 +244,9 @@ class ContinuousServeEngine:
             self._queue.popleft()
             self.page_table[b, :] = 0
             self.page_table[b, :len(pages)] = pages
+            req.times.admitted = self.clock()
             self._slots[b] = _Slot(req.rid, req.prompt, req.max_new,
-                                   req.stop_token, pages)
+                                   req.stop_token, pages, req.times)
 
     # ------------------------------------------------------------------
     # sampling
@@ -246,51 +282,63 @@ class ContinuousServeEngine:
     def step(self) -> List[StreamEvent]:
         """One engine tick: admit, one prefill chunk, one decode step."""
         events: List[StreamEvent] = []
-        self._admit()
+        with TraceAnnotation("serve.admit"):
+            self._admit()
 
-        # chunked prefill, interleaved: the oldest admitted slot with an
+        # chunked prefill, interleaved: the lowest-numbered slot with an
         # unfinished prompt absorbs one fixed-shape chunk per tick
         pf = [(b, s) for b, s in enumerate(self._slots)
               if s is not None and s.n_prefilled < len(s.prompt)]
         if pf:
             b, slot = pf[0]
-            CK = self.prefill_chunk
-            chunk = slot.prompt[slot.n_prefilled:slot.n_prefilled + CK]
-            toks = np.zeros((1, CK), np.int32)
-            toks[0, :len(chunk)] = chunk
-            logits, self.cache = self._prefill(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(self.page_table[b:b + 1]),
-                jnp.asarray([slot.length], np.int32),
-                jnp.asarray([len(chunk)], np.int32))
+            with TraceAnnotation("serve.prefill", rid=slot.rid):
+                CK = self.prefill_chunk
+                chunk = slot.prompt[slot.n_prefilled:slot.n_prefilled + CK]
+                toks = np.zeros((1, CK), np.int32)
+                toks[0, :len(chunk)] = chunk
+                # numpy, not Python lists: jnp.asarray of a list runs a
+                # convert program of its own on the device
+                logits, self.cache = self._prefill(
+                    self.params, self.cache, jnp.asarray(toks),
+                    jnp.asarray(self.page_table[b:b + 1]),
+                    jnp.asarray(np.array([slot.length], np.int32)),
+                    jnp.asarray(np.array([len(chunk)], np.int32)))
+            if slot.times.first_chunk is None:
+                slot.times.first_chunk = self.clock()
             slot.n_prefilled += len(chunk)
             slot.length += len(chunk)
             if slot.n_prefilled == len(slot.prompt):
-                self._emit(b, self._sample(np.asarray(logits)[0], slot),
-                           events)
+                with TraceAnnotation("serve.fetch"):
+                    row = np.asarray(logits)[0]
+                with TraceAnnotation("serve.sample"):
+                    self._emit(b, self._sample(row, slot), events)
 
         # one decode tick across every slot with a pending token
-        tokens = np.zeros((self.n_slots, 1), np.int32)
-        offsets = np.zeros((self.n_slots,), np.int32)
-        n_valid = np.zeros((self.n_slots,), np.int32)
-        live = []
-        for b, s in enumerate(self._slots):
-            if s is not None and s.last_token is not None:
-                tokens[b, 0] = s.last_token
-                offsets[b] = s.length
-                n_valid[b] = 1
-                live.append(b)
+        with TraceAnnotation("serve.decode"):
+            tokens = np.zeros((self.n_slots, 1), np.int32)
+            offsets = np.zeros((self.n_slots,), np.int32)
+            n_valid = np.zeros((self.n_slots,), np.int32)
+            live = []
+            for b, s in enumerate(self._slots):
+                if s is not None and s.last_token is not None:
+                    tokens[b, 0] = s.last_token
+                    offsets[b] = s.length
+                    n_valid[b] = 1
+                    live.append(b)
+            if live:
+                logits, self.cache = self._decode(
+                    self.params, self.cache, jnp.asarray(tokens),
+                    jnp.asarray(self.page_table), jnp.asarray(offsets),
+                    jnp.asarray(n_valid))
         if live:
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(tokens),
-                jnp.asarray(self.page_table), jnp.asarray(offsets),
-                jnp.asarray(n_valid))
-            lg = np.asarray(logits)
-            for b in live:
-                slot = self._slots[b]
-                slot.length += 1
-                slot.last_token = None
-                self._emit(b, self._sample(lg[b], slot), events)
+            with TraceAnnotation("serve.fetch"):
+                lg = np.asarray(logits)
+            with TraceAnnotation("serve.sample"):
+                for b in live:
+                    slot = self._slots[b]
+                    slot.length += 1
+                    slot.last_token = None
+                    self._emit(b, self._sample(lg[b], slot), events)
         return events
 
     def stream(self, prompts: List[List[int]], max_new: int = 32,

@@ -282,3 +282,133 @@ def test_continuous_rejects_stateful_families():
     cfg, m, params = _setup("xlstm_350m")
     with pytest.raises(ValueError, match="dense/moe"):
         ContinuousServeEngine(m, params, CTX)
+
+
+class _StepClock:
+    """Each reading is one more second."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+def _ints(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def test_engine_programs_are_named_and_scoped():
+    """A device trace names the two programs' modules and the paged KV
+    path's ops (the ``paged_kv`` and ``attention`` scopes' op_name)."""
+    cfg, m, params = _cont_setup()
+    eng = ContinuousServeEngine(m, params, CTX, n_slots=2, max_len=16,
+                                page_size=4, prefill_chunk=4)
+    n, w = eng.page_table.shape
+    for fn, rows, cols, name in ((eng._decode, n, 1, "decode_tick"),
+                                 (eng._prefill, 1, 4, "prefill_chunk")):
+        low = fn.lower(params, eng.cache, _ints(rows, cols), _ints(rows, w),
+                       _ints(rows), _ints(rows))
+        assert f"module @jit_{name} " in low.as_text()
+        text = low.compile().as_text()
+        assert f'op_name="jit({name})/' in text
+        assert "/paged_kv/" in text and "/attention/" in text
+    assert eng.trace_counts == {"decode": 1, "prefill": 1}
+
+
+def test_step_spans_nest_in_engine_step_in_order(tmp_path):
+    """A profile of a few ticks holds each phase's span inside the
+    caller's ``engine.step``, in the order the tick runs them."""
+    import re
+
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    cfg, m, params = _cont_setup()
+    eng = ContinuousServeEngine(m, params, CTX, n_slots=2, max_len=32,
+                                page_size=4, prefill_chunk=4)
+    eng.generate([[1, 2, 3]], max_new=2)            # compile outside
+    eng.submit([1, 2, 3, 4, 5], max_new=3)
+    eng.submit([6, 7], max_new=3)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while eng.pending:
+            with TraceAnnotation("engine.step"):
+                eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.glob("**/*.xplane.pb"))))
+    spans = sorted((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name,
+                    dict(ev.stats))
+                   for plane in pd.planes if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events
+                   if ev.name == "engine.step" or ev.name.startswith("serve."))
+    steps = [(a, b) for a, b, name, _ in spans if name == "engine.step"]
+    served = [s for s in spans if s[2] != "engine.step"]
+    assert len(steps) >= 4
+    assert all(any(a <= s[0] and s[1] <= b for a, b in steps) for s in served)
+    tick = re.compile(r"^admit( prefill( fetch sample)?)? decode"
+                      r"( fetch sample)?$")
+    for a, b in steps:
+        phases = " ".join(n[len("serve."):] for s, e, n, _ in served
+                          if a <= s and e <= b)
+        assert tick.match(phases), phases
+    assert {n for _, _, n, _ in served} == {
+        "serve.admit", "serve.prefill", "serve.decode", "serve.fetch",
+        "serve.sample"}
+    assert sorted({st["rid"] for _, _, n, st in served
+                   if n == "serve.prefill"}) == [1, 2]
+
+
+def test_request_times_on_the_engine_clock():
+    """Submitted, admitted and first chunk dispatched, one clock reading
+    each: the third request waits for a slot, the second for the other
+    two's prefill chunks."""
+    cfg, m, params = _cont_setup()
+    eng = ContinuousServeEngine(m, params, CTX, n_slots=2, max_len=16,
+                                page_size=4, prefill_chunk=4,
+                                clock=_StepClock())
+    rids = [eng.submit([1 + i] * 5, max_new=2) for i in range(3)]
+    while eng.pending:
+        eng.step()
+    got = {t.rid: (t.submitted, t.admitted, t.first_chunk)
+           for t in eng.request_times}
+    # tick 1 admits two (4, 5) and prefills the first (6); tick 2 its
+    # last chunk, and its last token finishes it; tick 3 admits the third
+    # into the freed slot (7), and the prefill line goes by slot, so the
+    # third's first chunk (8) runs before the second's (9)
+    assert [got[r] for r in rids] == [(1.0, 4.0, 6.0), (2.0, 5.0, 9.0),
+                                      (3.0, 7.0, 8.0)]
+
+
+def test_request_times_stay_bounded():
+    from repro.serve.scheduler import TIMES_KEPT
+
+    cfg, m, params = _cont_setup()
+    eng = ContinuousServeEngine(m, params, CTX, n_slots=2, max_len=16,
+                                page_size=4, prefill_chunk=4)
+    for _ in range(TIMES_KEPT + 10):
+        eng.cancel(eng.submit([1, 2], max_new=1))
+    eng.generate([[1, 2, 3]] * 3, max_new=2)
+    assert len(eng.request_times) == TIMES_KEPT
+    assert eng.request_times[0].rid == 13
+    assert eng.request_times[-1].first_chunk is not None
+    assert not eng.pending
+
+
+def test_prefill_step_runs_no_convert_program(caplog):
+    """A prefill tick's inputs go to the device as arrays: the tick
+    compiles and runs its own programs and no ``convert_element_type``."""
+    import logging
+
+    cfg, m, params = _cont_setup()
+    eng = ContinuousServeEngine(m, params, CTX, n_slots=2, max_len=16,
+                                page_size=4, prefill_chunk=4)
+    eng.submit([1, 2, 3, 4, 5, 6], max_new=1)
+    jax.clear_caches()
+    with jax.log_compiles(True), caplog.at_level(logging.WARNING):
+        eng.step()
+    compiled = [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("Compiling ")]
+    assert any("prefill_chunk" in c for c in compiled), compiled
+    assert not any("convert_element_type" in c for c in compiled), compiled
